@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from benchmarks.common import emit, pick, time_fn
+from repro.launch import roofline
 
 N = pick(65536, 1024)  # 256^2 full
 BATCH = pick(4, 2)
@@ -51,6 +52,11 @@ def main() -> None:
     from repro.dist.compat import make_mesh
     from repro.ops import plan
     from repro.ops.tune import PlanCache
+
+    # the tuner scores with its devices' peak rates; this CPU benchmark
+    # scores with the v5e entry, named explicitly
+    kind = jax.devices()[0].device_kind
+    roofline.PEAKS.setdefault(kind, roofline.PEAKS[roofline.V5E])
 
     # all tunes in this suite share the bench-local store (the deblur path
     # reaches the cache through the env var)

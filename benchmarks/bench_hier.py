@@ -10,7 +10,7 @@ one planned rfft matvec round and reports, per row,
   * the modeled production per-tier wire bytes per matvec at the cs_dryrun
     multi-host shape (n=4096^2 over H=2 hosts x D=8 devices): intra-host
     bytes ride ICI, and only the (H-1)/H cross-boundary fraction rides DCN
-    — the flat row pays DCN for every byte (launch/roofline.DCN_BW model).
+    — the flat row pays DCN for every byte (launch/roofline dcn_bw model).
 """
 
 from __future__ import annotations

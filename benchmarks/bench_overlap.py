@@ -20,8 +20,8 @@ import jax
 from repro.kernels.wire_pack.ops import wire_itemsize
 
 # bandwidths shared with the dry-run's roofline so the two models can
-# never diverge
-from repro.launch.roofline import HBM_BW, ICI_BW
+# never diverge; the production shape is a v5e pod
+from repro.launch.roofline import PEAKS, V5E
 
 from .common import emit, pick, time_fn
 
@@ -43,8 +43,8 @@ def _hidden_fraction_model(k: int, wire_dtype: str = "fp32") -> float:
     elem_bytes = 2 * wire_itemsize(wire_dtype)  # split-complex (re, im)
     a2a_bytes = (PROD_N1 // PROD_P) * nf_pad * elem_bytes
     stage1_bytes = (PROD_N1 // PROD_P) * (PROD_N2 * 4 + nf_pad * 8)  # r + w
-    wire_s = a2a_bytes / ICI_BW
-    window_s = stage1_bytes / HBM_BW
+    wire_s = a2a_bytes / PEAKS[V5E].ici_bw
+    window_s = stage1_bytes / PEAKS[V5E].hbm_bw
     hidden = min((k - 1) / k * wire_s, window_s)
     return hidden / wire_s
 

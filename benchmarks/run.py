@@ -55,6 +55,9 @@ def main() -> None:
 
     if args.smoke:
         os.environ["REPRO_BENCH_SMOKE"] = "1"
+    from repro.launch.env import configure_compile_cache
+
+    configure_compile_cache()
     suites = _load_suites()
     from benchmarks import common
 

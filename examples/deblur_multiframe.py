@@ -47,8 +47,9 @@ if __name__ == "__main__":  # XLA_FLAGS must land before jax imports
     args = ap.parse_args()
     if args.devices:
         os.environ["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={args.devices}"
-        )
+            os.environ.get("XLA_FLAGS", "")
+            + f" --xla_force_host_platform_device_count={args.devices}"
+        ).strip()
 
 import jax  # noqa: E402  (after XLA_FLAGS)
 import jax.numpy as jnp  # noqa: E402

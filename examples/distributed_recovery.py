@@ -33,7 +33,10 @@ if __name__ == "__main__":
                     help="elementwise iteration tail: XLA-fused jnp ops or "
                          "the fused cpadmm_tail Pallas kernel")
     args = ap.parse_args()
-    os.environ["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={args.devices}"
+    os.environ["XLA_FLAGS"] = (
+        os.environ.get("XLA_FLAGS", "")
+        + f" --xla_force_host_platform_device_count={args.devices}"
+    ).strip()
 
 import jax  # noqa: E402  (after XLA_FLAGS)
 import jax.numpy as jnp  # noqa: E402

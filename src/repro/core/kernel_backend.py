@@ -13,7 +13,11 @@ the same fused cpadmm_tail kernel inside the distributed step
 
 Step math is identical to ista.ista_step / admm.cpadmm_step — only the
 execution substrate changes:
-  * direct circulant matvec      -> kernels.circulant_matvec (time domain)
+  * direct circulant matvec      -> kernels.circulant_matvec (time domain;
+                                    CPISTA only — the kernel does not
+                                    compile for v5e, so the CPADMM step,
+                                    which tail='pallas' runs on the chip,
+                                    applies C through its spectrum)
   * threshold + dual update      -> kernels.soft_threshold   (fused VPU)
   * frequency-domain x-update    -> kernels.spectral_pointwise between rffts
   * whole elementwise iter tail  -> kernels.cpadmm_tail (v-update + threshold
@@ -80,7 +84,7 @@ def cpadmm_step_pallas(
     )
     x = jnp.fft.irfft(x_spec, n=n, axis=-1)
 
-    cx = circulant_matvec(op.circ.col, x, interpret=interpret)
+    cx = op.circ.matvec(x)  # spectral apply: never the direct kernel
     # the entire elementwise tail (v-update, threshold, both duals) is one
     # VMEM-resident kernel pass — kernels/cpadmm_tail
     v, z, mu, nu = fused_cpadmm_tail(

@@ -129,15 +129,17 @@ def make_stepper(
     ``prox=`` swaps the prior (repro.ops.prox); None defaults to the plan's
     ``prox`` and then to the paper's identity-basis soft threshold, which
     keeps the fused Pallas tails eligible.  A non-l1 prox composes the
-    z-update outside the fused kernels instead.
+    z-update outside the fused kernels, so a ``tail='pallas'`` plan refuses
+    it (``repro.ops.prox.check_tail``).
     """
     if prox is None and plan is not None:
         prox = getattr(plan, "prox", None)
+    tail = getattr(plan, "tail", "jnp") if plan is not None else "jnp"
+    prox_mod.check_tail(tail, prox)
     if plan is not None and getattr(plan, "is_distributed", False):
         return plan.build_stepper(
             problem, method, alpha=alpha, rho=rho, sigma=sigma, tau=tau, prox=prox
         )
-    tail = getattr(plan, "tail", "jnp") if plan is not None else "jnp"
     op, y = problem.op, problem.y
     if method in ("ista", "fista", "cpista"):
         tau_v = (
@@ -170,11 +172,10 @@ def make_stepper(
             tau2=jnp.asarray(1.0 if tau is None else tau, y.dtype),
         )
         const = admm_mod.cpadmm_setup(op, y, p)
-        if tail == "pallas" and prox_mod.is_l1(prox):
+        if tail == "pallas":
             # plan attribute tail='pallas' on the local backend: the fused
-            # kernels/cpadmm_tail substrate (core.kernel_backend).  The fused
-            # kernel bakes in the soft threshold, so it's only eligible for
-            # the l1 prior; other proxes take the composable jnp tail below.
+            # kernels/cpadmm_tail substrate (core.kernel_backend); check_tail
+            # above has already refused non-l1 priors for it.
             from repro.kernels.cpadmm_tail.ops import interpret_default
 
             from .kernel_backend import cpadmm_step_pallas

@@ -39,6 +39,82 @@ def sparse_signal(
     return vals * masks
 
 
+def _mix32(v: Array, k: Array) -> Array:
+    """Keyed 32-bit integer hash (xor key, then the lowbias32 finalizer)."""
+    v = v ^ k
+    v = v * jnp.uint32(0x7FEB352D)
+    v = v ^ (v >> 15)
+    v = v * jnp.uint32(0x846CA68B)
+    return v ^ (v >> 16)
+
+
+def _keyed_bijection(key: Array, n: int, rounds: int = 6):
+    """(F, F_inv): a keyed pseudo-random bijection of [0, n) and its
+    inverse, on uint32 arrays, built from elementwise integer ops only.
+
+    A ``rounds``-round Feistel network over the smallest even bit width
+    covering n, cycle-walked back into range where n is not a power of
+    four (walking F^-1 the same way inverts the walked F).
+    """
+    bits = max(2, (n - 1).bit_length())
+    bits += bits % 2
+    if bits > 32:
+        raise ValueError(f"n = {n} needs more than 32 index bits")
+    half = bits // 2
+    low = jnp.uint32((1 << half) - 1)
+    keys = jax.random.bits(key, (rounds,), jnp.uint32)
+
+    def fwd(v):
+        left, right = v >> half, v & low
+        for r in range(rounds):
+            left, right = right, left ^ (_mix32(right, keys[r]) & low)
+        return (left << half) | right
+
+    def inv(v):
+        left, right = v >> half, v & low
+        for r in reversed(range(rounds)):
+            left, right = right ^ (_mix32(left, keys[r]) & low), left
+        return (left << half) | right
+
+    def walked(step):
+        def apply(v):
+            v = step(v)
+            if n < (1 << bits):
+                v = jax.lax.while_loop(
+                    lambda v: jnp.any(v >= n),
+                    lambda v: jnp.where(v >= n, step(v), v),
+                    v,
+                )
+            return v
+
+        return apply
+
+    return walked(fwd), walked(inv)
+
+
+def random_subset_mask(key: Array, n: int, k: int) -> Array:
+    """Indicator (bool, length n) of a pseudo-random subset of exactly k
+    indices, drawn without a sort.
+
+    ``jax.random.permutation`` (behind :func:`sparse_signal` and
+    ``core.circulant.random_omega``) lowers to sorts, and the TPU compiler
+    takes tens of seconds per sort at n >= 2^18; a scatter or
+    ``jnp.nonzero`` is nearly as slow to compile.  Here index j is kept iff
+    ``F(j) < k`` for the keyed bijection F of :func:`_keyed_bijection`, so
+    exactly k indices pass and every step is elementwise.
+    :func:`random_subset_indices` lists the same subset.
+    """
+    fwd, _ = _keyed_bijection(key, n)
+    return fwd(jnp.arange(n, dtype=jnp.uint32)) < k
+
+
+def random_subset_indices(key: Array, n: int, k: int) -> Array:
+    """The subset of :func:`random_subset_mask` (same key) as k int32
+    indices, in pseudo-random order: ``F^-1(0 .. k-1)``, again sort-free."""
+    _, inv = _keyed_bijection(key, n)
+    return inv(jnp.arange(k, dtype=jnp.uint32)).astype(jnp.int32)
+
+
 def paper_regime(n: int) -> Tuple[int, int]:
     """Paper Sec. 6: m = n/2 measurements, k ~= n/10 nonzeros."""
     return n // 2, max(1, n // 10)

@@ -4,9 +4,8 @@ Module map (paper references are to "GPU-Accelerated Algorithms for
 Compressed Signals Recovery with Application to Astronomical Imagery
 Deblurring", arXiv:1707.02244):
 
-    compat     version-portable shard_map / mesh constructors (jax 0.4.x
-               through current), used by every entry point below and by the
-               subprocess test programs.
+    compat     shard_map / mesh constructors, used by every entry point
+               below and by the subprocess test programs.
     sharding   logical->physical named-axis sharding rules for the model
                stack (DEFAULT_RULES, rules_for_arch, activate_rules,
                constrain, grad_reduce_boundary).  This is the GSPMD side:

@@ -1,53 +1,32 @@
-"""Version-portable wrappers for the jax sharding API surface we use.
+"""Mesh and shard_map construction for the distributed layer, in one place.
 
-The distributed layer targets the modern spelling (``jax.shard_map`` with
-``check_vma``, ``jax.make_mesh(..., axis_types=...)``) but must also run on
-jax 0.4.x, where ``shard_map`` lives in ``jax.experimental.shard_map`` with a
-``check_rep`` keyword and meshes have no axis types.  Everything that builds
-a mesh or wraps a function in shard_map goes through this module so the rest
-of ``repro.dist`` (and the subprocess test programs) stays version-agnostic.
+Everything that builds a mesh or wraps a function in ``jax.shard_map`` goes
+through this module, so the rest of ``repro.dist`` (and the subprocess test
+programs) share one spelling: meshes with ``Auto`` axis types, and
+``shard_map`` with the replication check off by default.
 """
 
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.6: top-level export, `check_vma` keyword
-    from jax import shard_map as _shard_map
-
-    _KW = "check_vma"
-except ImportError:  # jax 0.4.x: experimental module, `check_rep` keyword
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _KW = "check_rep"
-
-try:  # explicit/auto axis types exist only on newer jax
-    from jax.sharding import AxisType  # noqa: F401
-
-    _HAS_AXIS_TYPES = True
-except ImportError:
-    AxisType = None
-    _HAS_AXIS_TYPES = False
+from jax.sharding import AxisType
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` with the replication-check keyword normalized.
+    """``jax.shard_map``; call sites pass ``check_vma=False``.
 
-    We default our call sites to ``check_vma=False``: the FFT layer uses
-    ``axis_index``-dependent twiddles, which the replication checker cannot
-    prove anything useful about.
+    The FFT layer uses ``axis_index``-dependent twiddles, which the
+    replication checker cannot prove anything useful about.
     """
-    kw = {_KW: check_vma}
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                         check_vma=check_vma)
 
 
 def make_mesh(axis_shapes, axis_names):
-    """``jax.make_mesh`` with Auto axis types where the API supports them."""
-    if _HAS_AXIS_TYPES:
-        return jax.make_mesh(
-            axis_shapes, axis_names, axis_types=(AxisType.Auto,) * len(axis_names)
-        )
-    return jax.make_mesh(axis_shapes, axis_names)
+    """``jax.make_mesh`` with Auto axis types."""
+    return jax.make_mesh(
+        axis_shapes, axis_names, axis_types=(AxisType.Auto,) * len(axis_names)
+    )
 
 
 def make_hier_mesh(data: int, host: int, device: int):

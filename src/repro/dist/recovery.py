@@ -129,17 +129,19 @@ def _transforms(
 def _tail(tail: str, prox=None):
     """Elementwise-tail dispatch: pure-jnp math or the fused Pallas kernel.
 
-    The Pallas path compiles for real on TPU and falls back to interpret
-    mode elsewhere (CPU tests), mirroring the repo-wide kernel convention.
-    The fused kernel bakes in the l1 soft threshold, so it is only taken
-    when ``is_l1(prox)``; any other elementwise prior composes through the
-    shared jnp tail (``core.admm.cpadmm_tail``) with the prox threaded in.
-    (Non-elementwise priors never reach here — the plan layer runs them at
-    the global level via :func:`dist_cpadmm_core`.)
+    The Pallas path compiles for real on TPU and runs in interpret mode
+    elsewhere (CPU tests), mirroring the repo-wide kernel convention.  The
+    fused kernel bakes in the l1 soft threshold, so ``tail='pallas'`` with
+    any other prior is refused (``repro.ops.prox.check_tail``); ``'jnp'``
+    composes every elementwise prior through the shared jnp tail
+    (``core.admm.cpadmm_tail``).  (Non-elementwise priors never reach here
+    — the plan layer runs them at the global level via
+    :func:`dist_cpadmm_core`.)
     """
-    from repro.ops.prox import is_l1
+    from repro.ops.prox import check_tail
 
-    if tail == "pallas" and is_l1(prox):
+    check_tail(tail, prox)
+    if tail == "pallas":
         from repro.kernels.cpadmm_tail.ops import fused_cpadmm_tail, interpret_default
 
         interpret = interpret_default()

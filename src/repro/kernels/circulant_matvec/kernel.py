@@ -22,10 +22,11 @@ Memory budget per step: BI*BJ (tile) + 2n (colx) + BJ (x) + BI (out) floats.
 With BI = BJ = 256 and n <= 2^20 this is well under a 16 MiB VMEM (the tile
 itself is 256 KiB); for larger n the FFT path takes over (see ops.py).
 
-The iota-gather (``jnp.take`` of a 1-D VMEM window) lowers on current Mosaic
-toolchains; an equivalent formulation via BJ unrolled dynamic slices is kept
-in ``_tile_via_slices`` for older toolchains and is covered by the same
-tests.
+Neither tile formulation compiles for a TPU v5e with the installed Mosaic
+toolchain: the iota-gather (``jnp.take`` of a 1-D VMEM window) is refused
+("Only 2D gather is supported"), and so are the BJ unrolled dynamic slices
+of ``_tile_via_slices``.  The kernel runs in interpret mode only (CPU
+tests); no chip path calls it.
 """
 
 from __future__ import annotations

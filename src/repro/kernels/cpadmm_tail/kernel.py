@@ -14,11 +14,13 @@ applies unchanged, so here the whole tail is one Pallas pass: six input
 streams tiled through VMEM once, four outputs written once, all
 intermediates (v, z) living only in registers/VMEM.
 
-Layout mirrors ``spectral_pointwise``: 1-D tiles over the flattened signal
-block, a leading batch axis (B signals through one operator) as the outer
-grid dimension.  The *operator* streams — d_diag always, pty when it is
-shared across the batch (one measurement mask, B signals) — stay resident
-per column-tile while the per-signal streams sweep past them.
+Layout mirrors ``spectral_pointwise``: the flattened signal block is folded
+into ``(rows, LANES)`` tiles (``kernels/_tiling``), and a leading batch axis
+(B signals through one operator) is the inner grid dimension.  The
+*operator* streams — d_diag always, pty when it is shared across the batch
+(one measurement mask, B signals) — keep one block index across the inner
+batch sweep, so each tile of them is fetched once while the per-signal
+streams sweep past it.
 """
 
 from __future__ import annotations
@@ -29,7 +31,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-DEFAULT_BLOCK = 1024
+from .._tiling import LANES, fold, fold_rows, unfold
 
 
 def _eta(v, gamma):
@@ -51,7 +53,7 @@ def _kernel(
     nu_out_ref[...] = nu + t2_ref[0] * (x - z)
 
 
-@functools.partial(jax.jit, static_argnames=("pty_batched", "block", "interpret"))
+@functools.partial(jax.jit, static_argnames=("pty_batched", "interpret"))
 def cpadmm_tail_pallas(
     d_diag: jax.Array,  # (L,) operator stream, shared across the batch
     pty: jax.Array,  # (L,) shared or (B, L) per-signal (see pty_batched)
@@ -65,7 +67,6 @@ def cpadmm_tail_pallas(
     tau2: jax.Array,
     *,
     pty_batched: bool = False,
-    block: int = DEFAULT_BLOCK,
     interpret: bool = True,
 ):
     """-> (v, z, mu', nu') with the shape of ``x``.
@@ -75,37 +76,31 @@ def cpadmm_tail_pallas(
     ``pty_batched``) are length-L operator vectors reused across the batch.
     """
     L = x.shape[-1]
-    pad = (-L) % block
-    if pad:
-        pads = lambda a: jnp.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, pad)])
-        d_diag, pty = pads(d_diag), pads(pty)
-        x, cx, mu, nu = pads(x), pads(cx), pads(mu), pads(nu)
-    n = x.shape[-1]
+    rows, rb = fold_rows(L)
+    d_diag, pty = fold(d_diag, rows), fold(pty, rows)
+    x, cx, mu, nu = (fold(a, rows) for a in (x, cx, mu, nu))
     dt = x.dtype
     scal = lambda s: jnp.broadcast_to(jnp.asarray(s, dt), (1,))
     rho, gamma, tau1, tau2 = scal(rho), scal(gamma), scal(tau1), scal(tau2)
-    batched = x.ndim == 2
-    if batched:
+    if x.ndim == 3:
         bsz = x.shape[0]
-        grid = (bsz, n // block)
-        # operator streams: resident per column-tile, reused across the batch
-        tile_op = pl.BlockSpec((block,), lambda b, i: i)
-        tile_sig = pl.BlockSpec((1, block), lambda b, i: (b, i))
-        scalar = pl.BlockSpec((1,), lambda b, i: 0)
-        out_shape = (bsz, n)
+        grid = (rows // rb, bsz)
+        # operator streams: one block index across the inner batch sweep
+        tile_op = pl.BlockSpec((rb, LANES), lambda i, b: (i, 0))
+        tile_sig = pl.BlockSpec((None, rb, LANES), lambda i, b: (b, i, 0))
+        scalar = pl.BlockSpec((1,), lambda i, b: (0,))
     else:
-        grid = (n // block,)
-        tile_op = pl.BlockSpec((block,), lambda i: i)
+        grid = (rows // rb,)
+        tile_op = pl.BlockSpec((rb, LANES), lambda i: (i, 0))
         tile_sig = tile_op
-        scalar = pl.BlockSpec((1,), lambda i: 0)
-        out_shape = (n,)
+        scalar = pl.BlockSpec((1,), lambda i: (0,))
     tile_pty = tile_sig if pty_batched else tile_op
     outs = pl.pallas_call(
         _kernel,
         grid=grid,
         in_specs=[tile_op, tile_pty] + [tile_sig] * 4 + [scalar] * 4,
         out_specs=[tile_sig] * 4,
-        out_shape=[jax.ShapeDtypeStruct(out_shape, dt)] * 4,
+        out_shape=[jax.ShapeDtypeStruct(x.shape, dt)] * 4,
         interpret=interpret,
     )(d_diag, pty, x, cx, mu, nu, rho, gamma, tau1, tau2)
-    return tuple(o[..., :L] for o in outs)
+    return tuple(unfold(o, L) for o in outs)

@@ -12,10 +12,12 @@ launching 4 separate elementwise ops over HBM (the paper's motivation for
 merging GPU kernels, Sec. 5).
 
 TPU has no complex dtype in Pallas: complex arrays travel as separate
-real/imag planes.  All blocks are 1-D tiles of the half-spectrum; a leading
-batch axis (B signals through one operator — the batched recovery pipeline)
-becomes the outer grid dimension, with the operator spectra c and b staying
-resident per column-tile while the per-signal streams sweep past them.
+real/imag planes.  Each plane of the half-spectrum is folded into
+``(rows, LANES)`` tiles (``kernels/_tiling``), so any length nf works; a
+leading batch axis (B signals through one operator — the batched recovery
+pipeline) becomes the inner grid dimension, with the operator spectra c and
+b keeping one block index across the batch sweep (fetched once per tile)
+while the per-signal streams sweep past them.
 """
 
 from __future__ import annotations
@@ -26,7 +28,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-DEFAULT_BLOCK = 512
+from .._tiling import LANES, fold, fold_rows, unfold
 
 
 def _kernel(
@@ -46,7 +48,7 @@ def _kernel(
     oi_ref[...] = b * xi
 
 
-@functools.partial(jax.jit, static_argnames=("block", "interpret"))
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def cpadmm_spectral_update(
     c_spec_r: jax.Array,
     c_spec_i: jax.Array,
@@ -58,7 +60,6 @@ def cpadmm_spectral_update(
     rho: jax.Array,
     sigma: jax.Array,
     *,
-    block: int = DEFAULT_BLOCK,
     interpret: bool = True,
 ):
     """-> (X_r, X_i): spectrum of the updated x.
@@ -67,38 +68,30 @@ def cpadmm_spectral_update(
     (vm, zn) are (nf,) or batched (B, nf) — one shared operator, B signals.
     """
     nf = c_spec_r.shape[-1]
-    pad = (-nf) % block
-    if pad:
-        pads = lambda a: jnp.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, pad)])
-        c_spec_r, c_spec_i, b_spec = pads(c_spec_r), pads(c_spec_i), pads(b_spec)
-        vm_r, vm_i, zn_r, zn_i = pads(vm_r), pads(vm_i), pads(zn_r), pads(zn_i)
-    n = c_spec_r.shape[-1]
-    rho = jnp.broadcast_to(jnp.asarray(rho, b_spec.dtype), (1,))
-    sigma = jnp.broadcast_to(jnp.asarray(sigma, b_spec.dtype), (1,))
-    batched = vm_r.ndim == 2
-    if batched:
+    rows, rb = fold_rows(nf)
+    c_spec_r, c_spec_i, b_spec = (fold(a, rows) for a in (c_spec_r, c_spec_i, b_spec))
+    vm_r, vm_i, zn_r, zn_i = (fold(a, rows) for a in (vm_r, vm_i, zn_r, zn_i))
+    dt = b_spec.dtype
+    rho = jnp.broadcast_to(jnp.asarray(rho, dt), (1,))
+    sigma = jnp.broadcast_to(jnp.asarray(sigma, dt), (1,))
+    if vm_r.ndim == 3:
         bsz = vm_r.shape[0]
-        grid = (bsz, n // block)
-        # operator spectra: resident per column-tile, reused across the batch
-        tile_op = pl.BlockSpec((block,), lambda b, i: i)
-        tile_sig = pl.BlockSpec((1, block), lambda b, i: (b, i))
-        scalar = pl.BlockSpec((1,), lambda b, i: 0)
-        out_shape = (bsz, n)
+        grid = (rows // rb, bsz)
+        # operator spectra: one block index across the inner batch sweep
+        tile_op = pl.BlockSpec((rb, LANES), lambda i, b: (i, 0))
+        tile_sig = pl.BlockSpec((None, rb, LANES), lambda i, b: (b, i, 0))
+        scalar = pl.BlockSpec((1,), lambda i, b: (0,))
     else:
-        grid = (n // block,)
-        tile_op = pl.BlockSpec((block,), lambda i: i)
+        grid = (rows // rb,)
+        tile_op = pl.BlockSpec((rb, LANES), lambda i: (i, 0))
         tile_sig = tile_op
-        scalar = pl.BlockSpec((1,), lambda i: 0)
-        out_shape = (n,)
+        scalar = pl.BlockSpec((1,), lambda i: (0,))
     out_r, out_i = pl.pallas_call(
         _kernel,
         grid=grid,
         in_specs=[tile_op] * 3 + [tile_sig] * 4 + [scalar, scalar],
         out_specs=[tile_sig, tile_sig],
-        out_shape=[
-            jax.ShapeDtypeStruct(out_shape, b_spec.dtype),
-            jax.ShapeDtypeStruct(out_shape, b_spec.dtype),
-        ],
+        out_shape=[jax.ShapeDtypeStruct(vm_r.shape, dt)] * 2,
         interpret=interpret,
     )(c_spec_r, c_spec_i, b_spec, vm_r, vm_i, zn_r, zn_i, rho, sigma)
-    return out_r[..., :nf], out_i[..., :nf]
+    return unfold(out_r, nf), unfold(out_i, nf)

@@ -12,7 +12,9 @@ Substrates:
     'pallas'  the kernels in kernel.py — one fused VMEM pass per direction
     'auto'    'pallas' compiled on TPU, 'jnp' elsewhere (interpret-mode
               Pallas inside every collective would be pure overhead on the
-              CPU test path; the kernel parity tests force 'pallas')
+              CPU test path; the kernel parity tests force 'pallas') — and
+              'jnp' for fp16 wires on TPU too: Mosaic has no float16 vector
+              layout on v5e, so the compiler refuses both kernels there
 """
 
 from __future__ import annotations
@@ -44,9 +46,10 @@ def interpret_default() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _resolve(substrate: str) -> str:
+def _resolve(substrate: str, wire_dtype) -> str:
     if substrate == "auto":
-        return "pallas" if jax.default_backend() == "tpu" else "jnp"
+        on_tpu = jax.default_backend() == "tpu"
+        return "pallas" if on_tpu and wire_dtype != jnp.float16 else "jnp"
     if substrate not in ("jnp", "pallas"):
         raise ValueError(
             f"wire pack substrate must be 'auto', 'jnp' or 'pallas', "
@@ -63,7 +66,7 @@ def pack_wire(z, wire_dtype: str, substrate: str = "auto", interpret=None):
     but demotes nothing.
     """
     dt = WIRE_DTYPES[wire_dtype]
-    if _resolve(substrate) == "jnp":
+    if _resolve(substrate, dt) == "jnp":
         return pack_wire_ref(z, dt)
     shape = z.shape
     L = 1
@@ -81,7 +84,7 @@ def pack_wire(z, wire_dtype: str, substrate: str = "auto", interpret=None):
 def unpack_wire(w, out_dtype=jnp.complex64, substrate: str = "auto",
                 interpret=None):
     """(2, ...) wire planes -> complex payload, promoted via float32."""
-    if _resolve(substrate) == "jnp":
+    if _resolve(substrate, w.dtype) == "jnp":
         return unpack_wire_ref(w, out_dtype)
     shape = w.shape[1:]
     L = 1

@@ -1,6 +1,8 @@
 import os
 
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=512")
+os.environ["XLA_FLAGS"] = (
+    os.environ.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=512"
+).strip()
 
 """Dry-run + roofline for the paper's own workload on the production mesh.
 
@@ -36,14 +38,14 @@ axis spans hosts and every cross-host byte rides DCN instead of ICI:
 
     mh_flat     wire_bf16 lowered over the factored (host, device) axis as
                 one monolithic all-to-all — every transpose byte crosses
-                the host boundary and is charged at DCN_BW
+                the host boundary and is charged at the DCN rate
     mh_hier     the two-stage hierarchical exchange (hier_axes=(H, D),
                 dist/fft): full payload intra-host on ICI, only the
                 (H-1)/H cross-boundary fraction on DCN as collective-
                 permutes, with its own inter_wire_dtype
 
     per-tier bytes are read off the compiled HLO (collective-permute = the
-    DCN hop), and the two-tier model (roofline.DCN_BW) scores both.
+    DCN hop), and the two-tier model (roofline PEAKS dcn_bw) scores both.
 
 This is the §Perf hillclimb cell for the paper's technique: the printed
 per-signal FFT-flop and wire-byte ratios are the measured value of each
@@ -64,7 +66,7 @@ from repro.dist.fft import padded_rfft_len
 from repro.dist.recovery import DistCpadmmState
 from repro.launch.hlo_analysis import analyze_compiled
 from repro.launch.mesh import make_production_mesh
-from repro.launch.roofline import model_block_times
+from repro.launch.roofline import PEAKS, V5E, model_block_times
 from repro.ops import plan_from_parts
 from repro.ops.plan import _transform_extent
 
@@ -118,7 +120,8 @@ def analyze(compiled, iters, batch, overlap=1, dcn="none"):
     cp_bytes = c.collective_bytes.get("collective-permute", 0)
     dcn_bytes = {"none": 0.0, "permute": float(cp_bytes),
                  "all": float(a2a_bytes)}[dcn]
-    times = model_block_times(c, overlap, dcn_bytes=dcn_bytes)
+    # the dry-run models the v5e production pod, whatever compiles it
+    times = model_block_times(c, overlap, dcn_bytes=dcn_bytes, peaks=PEAKS[V5E])
     return {
         "flops_per_dev": c.flops,
         "bytes_per_dev": c.bytes,

@@ -1,10 +1,12 @@
 import os
 
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["XLA_FLAGS"] = (
+    os.environ.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=512"
+).strip()
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
-The two lines above MUST stay first — jax locks the device count on first
+The statements above MUST stay first — jax locks the device count on first
 init, and the production meshes need 512 placeholder devices.  Run as
 
     PYTHONPATH=src python -m repro.launch.dryrun --arch codeqwen1.5-7b \
@@ -132,8 +134,6 @@ def run_cell(arch: str, shape: str, mesh_kind: str, out_path: str) -> dict:
             mem_dict[field] = int(v)
 
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):  # jax 0.4.x: one dict per module
-        cost = cost[0] if cost else {}
     cost_dict = {
         k: float(v)
         for k, v in cost.items()

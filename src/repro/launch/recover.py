@@ -38,7 +38,6 @@ Per-frame PSNR / normalized MSE are reported after the solve.
 from __future__ import annotations
 
 import argparse
-import os
 import sys
 
 if __name__ == "__main__":  # --fake-devices must land before jax imports
@@ -46,9 +45,10 @@ if __name__ == "__main__":  # --fake-devices must land before jax imports
     _pre.add_argument("--fake-devices", type=int, default=0)
     _n, _ = _pre.parse_known_args()
     if _n.fake_devices:
-        os.environ["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={_n.fake_devices}"
-        )
+        from repro.launch.env import append_flag
+
+        append_flag("XLA_FLAGS",
+                    f"--xla_force_host_platform_device_count={_n.fake_devices}")
 
 import time
 
@@ -63,6 +63,7 @@ from repro.core import (
     solve_until,
 )
 from repro.data.synthetic import paper_regime, sparse_signal
+from repro.launch.env import configure_compile_cache
 
 METHODS = ("cpadmm", "ista", "fista")
 
@@ -288,6 +289,7 @@ def report_deblur(dp, x_hat) -> None:
 
 def main(argv=None):
     args = _parser().parse_args(argv)
+    configure_compile_cache()
     if args.ckpt_dir is None:
         args.ckpt_dir = ("artifacts/recover_deblur_ckpt" if args.deblur
                          else "artifacts/recover_ckpt")

@@ -1,10 +1,12 @@
 """Roofline derivation from the dry-run artifacts (assignment §ROOFLINE).
 
-Hardware model: TPU v5e — 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link ICI.
+Hardware model: one entry of :data:`PEAKS`, keyed by the device kind JAX
+reports (``jax.devices()[0].device_kind``); a kind with no entry is an
+error, never a default.
 
-    compute term    = flops_per_device / PEAK_FLOPS
-    memory term     = hbm_bytes_per_device / HBM_BW
-    collective term = wire_bytes_per_device / ICI_BW
+    compute term    = flops_per_device / peaks.flops
+    memory term     = hbm_bytes_per_device / peaks.hbm_bw
+    collective term = wire_bytes_per_device / peaks.ici_bw
 
 flops/bytes come from the trip-count-aware HLO walk (launch/hlo_analysis.py —
 XLA's own cost_analysis counts while bodies once, see that module's header).
@@ -17,9 +19,9 @@ payloads cross as 2-byte planes) are modeled at their true wire size with
 no special-casing here.
 
 The collective term is two-tier: bytes that cross a host boundary ride the
-datacenter network at ``DCN_BW`` instead of ICI, so callers pass the
+datacenter network at ``dcn_bw`` instead of ICI, so callers pass the
 cross-host fraction as ``model_block_times(..., dcn_bytes=...)`` and the
-term splits into ``ici_collective_s + dcn_collective_s``.  Hierarchical
+term splits into ``ici_collective_s + dcn_collective_s`` (``peaks.dcn_bw``).  Hierarchical
 plans (``hier_axes=``, repro.dist.fft) put exactly the inter-host hop into
 ``collective-permute`` ops, so their DCN bytes are read straight off the
 HLO walk; a flat all-to-all spanning hosts charges its whole payload to
@@ -33,19 +35,44 @@ the single-fabric numbers, keeping pre-split tune-cache entries and
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import glob
 import json
 import os
 from typing import List
 
-PEAK_FLOPS = 197e12  # bf16 / chip
-HBM_BW = 819e9  # B/s
-ICI_BW = 50e9  # B/s per link
-# Datacenter network between hosts.  ~100 Gb/s NIC per chip pair on a v5e
-# pod slice boundary -> 12.5 GB/s, derated 2x for the a2a incast pattern.
-# Well under ICI_BW / H for small host counts, which is the regime where the
-# two-stage hierarchical exchange (1/H of the bytes on DCN) wins.
-DCN_BW = 6.25e9  # B/s per link
+
+@dataclasses.dataclass(frozen=True)
+class Peaks:
+    """Per-chip peak rates of one device kind (bytes and ops per second)."""
+
+    flops: float  # bf16 FLOP/s
+    hbm_bw: float  # HBM bytes/s
+    ici_bw: float  # chip-to-chip bytes/s per link
+    dcn_bw: float  # host-to-host bytes/s per link (modelled, see below)
+
+
+# Source: Google Cloud TPU documentation, "TPU v5e": 197 TFLOP/s bf16,
+# 16 GB HBM at 819 GB/s, 1,600 Gbit/s chip-to-chip interconnect (4 links
+# -> 50 GB/s each).  The DCN rate is a model, not a published peak: ~100
+# Gb/s NIC per chip pair on a slice boundary -> 12.5 GB/s, derated 2x for
+# the all-to-all incast pattern.
+PEAKS = {
+    "TPU v5 lite": Peaks(flops=197e12, hbm_bw=819e9, ici_bw=50e9, dcn_bw=6.25e9),
+}
+V5E = "TPU v5 lite"
+
+
+def peaks_for(device_kind: str) -> Peaks:
+    """The :data:`PEAKS` entry for ``device_kind``; unknown kinds raise."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no peak rates for device kind {device_kind!r}; known kinds: "
+            f"{sorted(PEAKS)}"
+        ) from None
+
 
 WIRE_MULT = {
     "all-reduce": 2.0,  # ring: reduce-scatter + all-gather
@@ -59,15 +86,18 @@ WIRE_MULT = {
 from repro.configs.registry import SHAPES  # noqa: E402
 
 
-def model_block_times(cost, overlap: int = 1, dcn_bytes: float = 0.0) -> dict:
+def model_block_times(
+    cost, overlap: int = 1, dcn_bytes: float = 0.0, *, peaks: Peaks
+) -> dict:
     """Roofline terms + the hidden-collective overlap model for one compiled
     block, from a :class:`repro.launch.hlo_analysis.Cost`.
 
     The shared scoring core of ``launch/cs_dryrun.py`` (the dry-run tables)
     and ``ops/tune.py`` (candidate ranking) — one cost model, two callers.
 
+    ``peaks`` is the :data:`PEAKS` entry of the device the block runs on.
     ``dcn_bytes`` is the portion of the wire bytes that crosses a host
-    boundary and therefore rides ``DCN_BW`` instead of ``ICI_BW`` (clamped
+    boundary and therefore rides ``peaks.dcn_bw`` instead of ``ici_bw`` (clamped
     to the total — a caller can pass raw HLO collective-permute bytes
     without worrying about multipliers).  The default 0.0 subtracts and
     adds exact float zeros, so single-fabric scores are reproduced
@@ -86,11 +116,11 @@ def model_block_times(cost, overlap: int = 1, dcn_bytes: float = 0.0) -> dict:
     wire = sum(
         WIRE_MULT.get(op, 1.0) * b for op, b in cost.collective_bytes.items()
     )
-    compute_s = cost.flops / PEAK_FLOPS
-    memory_s = cost.bytes / HBM_BW
+    compute_s = cost.flops / peaks.flops
+    memory_s = cost.bytes / peaks.hbm_bw
     dcn_wire = min(float(dcn_bytes), wire)
-    ici_s = (wire - dcn_wire) / ICI_BW
-    dcn_s = dcn_wire / DCN_BW
+    ici_s = (wire - dcn_wire) / peaks.ici_bw
+    dcn_s = dcn_wire / peaks.dcn_bw
     collective_s = ici_s + dcn_s
     local_s = max(compute_s, memory_s)
     hidden_s = min((overlap - 1) / overlap * collective_s, 0.5 * local_s)
@@ -124,14 +154,16 @@ def model_flops(rec: dict) -> float:
 
 
 def derive(rec: dict) -> dict:
+    """Roofline terms of one dry-run cell; the dry-runs model a v5e pod."""
+    peaks = PEAKS[V5E]
     w = rec["hlo_walk"]
     n_dev = rec["n_devices"]
-    compute_s = w["flops"] / PEAK_FLOPS
-    memory_s = w["bytes"] / HBM_BW
+    compute_s = w["flops"] / peaks.flops
+    memory_s = w["bytes"] / peaks.hbm_bw
     wire = sum(
         WIRE_MULT.get(op, 1.0) * b for op, b in w["collective_bytes"].items()
     )
-    collective_s = wire / ICI_BW
+    collective_s = wire / peaks.ici_bw
     terms = {"compute": compute_s, "memory": memory_s, "collective": collective_s}
     bottleneck = max(terms, key=terms.get)
     mf = model_flops(rec)

@@ -25,7 +25,6 @@ recycling statistics per bucket.
 from __future__ import annotations
 
 import argparse
-import os
 import sys
 
 if __name__ == "__main__":  # --fake-devices must land before jax imports
@@ -33,9 +32,10 @@ if __name__ == "__main__":  # --fake-devices must land before jax imports
     _pre.add_argument("--fake-devices", type=int, default=0)
     _n, _ = _pre.parse_known_args()
     if _n.fake_devices:
-        os.environ["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={_n.fake_devices}"
-        )
+        from repro.launch.env import append_flag
+
+        append_flag("XLA_FLAGS",
+                    f"--xla_force_host_platform_device_count={_n.fake_devices}")
 
 import jax
 
@@ -91,6 +91,9 @@ def _parser() -> argparse.ArgumentParser:
 
 def main(argv=None):
     args = _parser().parse_args(argv)
+    from repro.launch.env import configure_compile_cache
+
+    configure_compile_cache()
 
     from repro.core.circulant import partial_gaussian_circulant
     from repro.data.synthetic import paper_regime

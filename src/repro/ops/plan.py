@@ -189,6 +189,7 @@ class PlanConfig:
                 f"repro.ops.prox.Prox (apply(x, gamma) + tag); got "
                 f"{self.prox!r}"
             )
+        prox_mod.check_tail(self.tail, self.prox)
         if not isinstance(self.overlap, int) or self.overlap < 1:
             raise ValueError(f"overlap must be a positive int, got {self.overlap!r}")
         if self.wire_dtype not in WIRE_DTYPES:

@@ -344,6 +344,20 @@ def is_l1(prox) -> bool:
     return prox is None or type(prox) is L1Prox
 
 
+def check_tail(tail: str, prox) -> None:
+    """Reject ``tail='pallas'`` with a non-l1 prior.
+
+    The fused CPADMM tail kernel bakes in the l1 soft threshold; running
+    another prior under a plan that asked for the kernel would have to
+    swap in the jnp tail behind the caller's back."""
+    if tail == "pallas" and not is_l1(prox):
+        raise ValueError(
+            f"tail='pallas' runs the fused l1 CPADMM tail kernel and cannot "
+            f"apply the prior {getattr(prox, 'tag', prox)!r}; use "
+            f"tail='jnp' for non-l1 priors"
+        )
+
+
 def is_elementwise(prox) -> bool:
     """True when the prox acts coordinate-wise (safe inside a shard_map)."""
     return prox is None or bool(getattr(prox, "elementwise", False))

@@ -72,6 +72,7 @@ from .plan import (
     _transform_extent,
     plan_from_parts,
 )
+from .prox import is_l1
 
 SDS = jax.ShapeDtypeStruct
 
@@ -293,10 +294,12 @@ def candidate_configs(
     overlaps = (pins["overlap"],) if "overlap" in pins else OVERLAPS
     if "tail" in pins:
         tails: Tuple[str, ...] = (pins["tail"],)
-    elif jax.default_backend() == "tpu":
+    elif jax.default_backend() == "tpu" and is_l1(pins.get("prox")):
         tails = ("jnp", "pallas")
     else:
-        tails = ("jnp",)  # the pallas tail interprets (slowly) off-TPU
+        # the pallas tail interprets (slowly) off-TPU, and its fused kernel
+        # applies only the l1 prior
+        tails = ("jnp",)
     fuseds = (pins["fused"],) if "fused" in pins else (True,)
     # default wire sweep stops at bf16: same exponent range as fp32, so the
     # plan()-side precision guard essentially always accepts it; fp16 (more
@@ -443,15 +446,18 @@ def score_candidates(
 
     One compile + HLO walk per overlap-group; the overlap sweep is analytic
     (:func:`model_block_times` on the shared K=1 cost).  Cross-host bytes
-    (:func:`_dcn_bytes`) are charged at ``DCN_BW`` — this is what splits
-    flat from hierarchical candidates on a multi-host mesh.  Ties break
+    (:func:`_dcn_bytes`) are charged at the DCN rate of the mesh's device
+    kind (``roofline.peaks_for``; a kind with no peak entry raises) — this
+    is what splits flat from hierarchical candidates on a multi-host mesh.
+    Ties break
     toward the *simpler* config — lower overlap, then rfft off — so a mesh
     where a knob is cost-neutral (e.g. a 1-device axis, where collectives
     vanish) keeps the defaults rather than picking complexity for nothing.
     """
     from repro.launch.hlo_analysis import analyze_compiled
-    from repro.launch.roofline import model_block_times
+    from repro.launch.roofline import model_block_times, peaks_for
 
+    peaks = peaks_for(mesh.devices.flat[0].device_kind)
     costs: Dict[tuple, Any] = {}
     scored: List[Tuple[float, PlanConfig, dict]] = []
     for cfg in candidates:
@@ -462,7 +468,7 @@ def score_candidates(
             COUNTERS["scored"] += 1
         times = model_block_times(
             costs[gk], cfg.overlap,
-            dcn_bytes=_dcn_bytes(costs[gk], cfg, mesh),
+            dcn_bytes=_dcn_bytes(costs[gk], cfg, mesh), peaks=peaks,
         )
         scored.append((times["modeled_total_s"], cfg, times))
     scored.sort(key=lambda t: (t[0], t[1].overlap, t[1].rfft, t[1].describe()))

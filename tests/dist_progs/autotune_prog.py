@@ -17,7 +17,9 @@ the two properties that need a non-trivial mesh to mean anything:
 
 import os
 
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+os.environ["XLA_FLAGS"] = (
+    os.environ.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=8"
+).strip()
 
 import re
 import tempfile
@@ -29,7 +31,12 @@ from repro.core import RecoveryProblem, solve
 from repro.core.circulant import PartialCirculant, gaussian_circulant
 from repro.data.synthetic import paper_regime, sparse_signal
 from repro.dist.compat import make_mesh
+from repro.launch import roofline
 from repro.ops import plan, tune
+
+# the tuner scores with its devices' peak rates; these CPU devices are
+# scored with the v5e entry, named explicitly
+roofline.PEAKS[jax.devices()[0].device_kind] = roofline.PEAKS[roofline.V5E]
 
 mesh = make_mesh((8,), ("model",))
 n1, n2 = 32, 32

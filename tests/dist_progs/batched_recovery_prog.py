@@ -8,7 +8,9 @@ every transform is still exactly one all-to-all for the whole batch.
 
 import os
 
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+os.environ["XLA_FLAGS"] = (
+    os.environ.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=8"
+).strip()
 
 import jax
 import jax.numpy as jnp

@@ -11,7 +11,9 @@ Checks (8 fake devices, 'data' axis):
 
 import os
 
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+os.environ["XLA_FLAGS"] = (
+    os.environ.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=8"
+).strip()
 
 
 import jax

@@ -30,7 +30,9 @@ inter wire stays within WIRE_ERROR_BOUND of the fp32-wire solve.)
 import os
 import tempfile
 
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+os.environ["XLA_FLAGS"] = (
+    os.environ.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=8"
+).strip()
 os.environ["REPRO_PLAN_CACHE"] = os.path.join(
     tempfile.mkdtemp(prefix="hier_prog_cache"), "plan_cache.json"
 )
@@ -47,6 +49,11 @@ from repro.dist.compat import make_hier_mesh, make_mesh
 from repro.ops import plan
 from repro.ops.plan import WIRE_ERROR_BOUND
 from repro.ops.tune import tuned_config
+from repro.launch import roofline
+
+# the tuner scores with its devices' peak rates; these CPU devices are
+# scored with the v5e entry, named explicitly
+roofline.PEAKS[jax.devices()[0].device_kind] = roofline.PEAKS[roofline.V5E]
 
 H, D = 2, 2
 mesh = make_hier_mesh(2, H, D)  # data=2 x host=2 x device=2

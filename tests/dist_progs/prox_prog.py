@@ -12,7 +12,9 @@ partitions the prox's rolls over the same mesh.  The TV map-making stack
 
 import os
 
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+os.environ["XLA_FLAGS"] = (
+    os.environ.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=8"
+).strip()
 
 import jax
 import jax.numpy as jnp

@@ -11,7 +11,9 @@ match its solo tolerance-stopped solve to 1e-5 relative.
 import dataclasses
 import os
 
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+os.environ["XLA_FLAGS"] = (
+    os.environ.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=8"
+).strip()
 
 import jax
 import jax.numpy as jnp

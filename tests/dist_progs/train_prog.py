@@ -3,7 +3,9 @@ runs collectives; checkpoint save -> elastic restore onto a different mesh."""
 
 import os
 
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+os.environ["XLA_FLAGS"] = (
+    os.environ.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=8"
+).strip()
 
 import tempfile
 

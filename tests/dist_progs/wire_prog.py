@@ -15,7 +15,9 @@ ISSUE 8 acceptance, measured on the compiled HLO rather than modeled:
 
 import os
 
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+os.environ["XLA_FLAGS"] = (
+    os.environ.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=8"
+).strip()
 
 import re
 

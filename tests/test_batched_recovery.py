@@ -44,9 +44,15 @@ TUNED = dict(alpha=1e-4, rho=0.01, sigma=0.01)
 
 
 def _batched_problem(n=256, batch=(), seed=0):
+    # drawn under JAX's original threefry stream, on which these instances
+    # were chosen (JAX 0.9 made the partitionable stream the default; its
+    # redraw of the seed-0 instance puts a tolerance stop on a knife edge,
+    # so batched and unbatched runs stop 17 iterations apart)
     m, k = paper_regime(n)
-    x = sparse_signal(jax.random.PRNGKey(seed), n, k, batch=batch)
-    op = partial_gaussian_circulant(jax.random.PRNGKey(seed + 1), n, m, normalize=True)
+    with jax.threefry_partitionable(False):
+        x = sparse_signal(jax.random.PRNGKey(seed), n, k, batch=batch)
+        op = partial_gaussian_circulant(jax.random.PRNGKey(seed + 1), n, m,
+                                        normalize=True)
     return RecoveryProblem(op=op, y=op.matvec(x), x_true=x)
 
 

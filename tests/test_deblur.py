@@ -1,5 +1,7 @@
 """Compressed deblurring application tests (paper Sec. 7)."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -18,6 +20,13 @@ from repro.core.deblur import (
 from repro.data.synthetic import starfield
 
 SOLVE_KW = dict(alpha=1e-3, rho=0.01, sigma=0.01)
+
+# The golden pins and the multiframe bound were recorded with data drawn
+# under JAX's original threefry stream.  JAX 0.9 switched the default
+# (``jax_threefry_partitionable=True``), which redraws every starfield,
+# operator and subset from the same keys; the tests that pin values build
+# their data inside this context so the pinned instances stay the same.
+recorded_stream = functools.partial(jax.threefry_partitionable, False)
 
 
 def _rel(got, want):
@@ -106,10 +115,12 @@ GOLDEN = {
 
 
 def _golden_problem(sensing, h, w):
-    img = starfield(jax.random.PRNGKey(0), h=h, w=w, density=0.08, n_blobs=3)
-    return build_deblur_problem(
-        jax.random.PRNGKey(1), img, blur_order=5, subsample=0.5, sensing=sensing
-    )
+    with recorded_stream():
+        img = starfield(jax.random.PRNGKey(0), h=h, w=w, density=0.08, n_blobs=3)
+        return build_deblur_problem(
+            jax.random.PRNGKey(1), img, blur_order=5, subsample=0.5,
+            sensing=sensing,
+        )
 
 
 def _check_golden(p, x, case):
@@ -151,11 +162,13 @@ def test_deblur_golden_psf_families(blur_kind, order):
     like the moving-average cases, through the planned (rfft) path."""
     from repro.dist.compat import make_mesh
 
-    img = starfield(jax.random.PRNGKey(0), h=32, w=32, density=0.08, n_blobs=3)
-    p = build_deblur_problem(
-        jax.random.PRNGKey(1), img, blur_order=order, subsample=0.5,
-        sensing="romberg", blur_kind=blur_kind,
-    )
+    with recorded_stream():
+        img = starfield(jax.random.PRNGKey(0), h=32, w=32, density=0.08,
+                        n_blobs=3)
+        p = build_deblur_problem(
+            jax.random.PRNGKey(1), img, blur_order=order, subsample=0.5,
+            sensing="romberg", blur_kind=blur_kind,
+        )
     prob = RecoveryProblem(op=p.op, y=p.y, x_true=img.reshape(-1))
     x_ref, _ = solve(prob, "cpadmm", iters=800, record_every=800, **SOLVE_KW)
     golden_psnr, golden_nmse, golden_rel = GOLDEN_PSF[(blur_kind, order)]
@@ -372,13 +385,16 @@ def test_multiframe_deblur_batched_recovery():
     """A (F, H, W) stack through one shared optic recovers per frame with a
     single batched solve; metrics come back with the frame axis."""
     F = 3
-    imgs = jnp.stack(
-        [starfield(jax.random.PRNGKey(10 + i), h=16, w=16, density=0.08, n_blobs=2)
-         for i in range(F)]
-    )
-    p = build_multiframe_deblur_problem(
-        jax.random.PRNGKey(4), imgs, blur_order=3, subsample=0.6, sensing="romberg"
-    )
+    with recorded_stream():
+        imgs = jnp.stack(
+            [starfield(jax.random.PRNGKey(10 + i), h=16, w=16, density=0.08,
+                       n_blobs=2)
+             for i in range(F)]
+        )
+        p = build_multiframe_deblur_problem(
+            jax.random.PRNGKey(4), imgs, blur_order=3, subsample=0.6,
+            sensing="romberg",
+        )
     assert p.y.shape == (F, p.op.m)
     prob = RecoveryProblem(op=p.op, y=p.y, x_true=imgs.reshape(F, -1))
     x, _ = solve(prob, "cpadmm", iters=500, record_every=500,

@@ -217,8 +217,9 @@ def test_solver_none_vs_l1prox_bitwise(method):
 
 def test_cpadmm_pallas_tail_l1_only():
     """tail='pallas' stays on the fused kernel for the l1 prior (bit-exact
-    vs the jnp tail in interpret mode) and silently composes the jnp tail
-    for a non-l1 prox instead of crashing the fused kernel."""
+    vs the jnp tail in interpret mode) and refuses a non-l1 prox — the
+    fused kernel bakes in the soft threshold, so the plan would otherwise
+    have to run the jnp tail in place of the kernel it asked for."""
     prob = _problem(batch=1)
     prob = RecoveryProblem(op=prob.op, y=prob.y[0], x_true=prob.x_true[0])
     pl_jnp = plan(prob.op, tail="jnp")
@@ -228,14 +229,17 @@ def test_cpadmm_pallas_tail_l1_only():
     x_p, _ = solve(prob, "cpadmm", iters=20, record_every=20, plan=pl_pal,
                    **SOLVE_KW)
     assert _rel(x_p, x_j) < 1e-6
-    # non-l1 prox through the pallas-tagged plan: composable fallback
+    # a non-l1 prox through the pallas-tagged plan is an error, not a
+    # silent jnp fallback — at solve time and in the plan config
     prox = NonNegL1Prox()
-    x_f, _ = solve(prob, "cpadmm", iters=20, record_every=20, plan=pl_pal,
-                   prox=prox, **SOLVE_KW)
+    with pytest.raises(ValueError, match="tail='pallas'"):
+        solve(prob, "cpadmm", iters=20, record_every=20, plan=pl_pal,
+              prox=prox, **SOLVE_KW)
+    with pytest.raises(ValueError, match="tail='pallas'"):
+        plan(prob.op, tail="pallas", prox=prox)
     x_r, _ = solve(prob, "cpadmm", iters=20, record_every=20, plan=pl_jnp,
                    prox=prox, **SOLVE_KW)
-    np.testing.assert_array_equal(np.asarray(x_f), np.asarray(x_r))
-    assert float(x_f.min()) >= 0.0
+    assert float(x_r.min()) >= 0.0
 
 
 @pytest.mark.parametrize(
@@ -389,3 +393,34 @@ def test_serve_buckets_split_on_prox():
     k_tv = server.bucket_key(req("b", PlanConfig(prox=TVProx(shape=(16, 16)))))
     k_wv = server.bucket_key(req("c", PlanConfig(prox=WaveletProx())))
     assert len({k_l1, k_tv, k_wv}) == 3
+
+
+@pytest.mark.parametrize(
+    "prox",
+    [NonNegL1Prox(), TVProx(shape=(16, 16)), WaveletProx(levels=2, wavelet="haar")],
+    ids=lambda p: p.tag,
+)
+def test_plan_config_rejects_pallas_tail_with_non_l1_prior(prox):
+    """PlanConfig.validate() is where tail='pallas' + non-l1 prior dies —
+    local and distributed alike; the l1 priors stay eligible."""
+    with pytest.raises(ValueError, match="tail='pallas'"):
+        PlanConfig(tail="pallas", prox=prox).validate(distributed=False)
+    with pytest.raises(ValueError, match="tail='pallas'"):
+        PlanConfig(tail="pallas", prox=prox).validate(distributed=True)
+    PlanConfig(tail="jnp", prox=prox).validate(distributed=False)
+    PlanConfig(tail="pallas", prox=L1Prox()).validate(distributed=False)
+
+
+def test_tuner_never_offers_pallas_tail_for_non_l1(monkeypatch):
+    """On the chip the tuner races jnp vs pallas tails — but only for the
+    l1 prior the fused kernel implements."""
+    from repro.dist.compat import make_mesh
+    from repro.ops import tune
+
+    monkeypatch.setattr(tune.jax, "default_backend", lambda: "tpu")
+    mesh = make_mesh((1,), ("model",))
+    op = _problem().op
+    l1 = tune.candidate_configs(op, mesh, pins={})
+    assert {c.tail for c in l1} == {"jnp", "pallas"}
+    nn = tune.candidate_configs(op, mesh, pins={"prox": NonNegL1Prox()})
+    assert {c.tail for c in nn} == {"jnp"}

@@ -12,6 +12,13 @@ import pytest
 from repro.launch import recover
 
 
+@pytest.fixture(autouse=True)
+def _placed_compile_cache(monkeypatch, tmp_path):
+    """main() configures the compile cache; a placed directory keeps it
+    from re-pointing this test process's JAX config."""
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "jax_cache"))
+
+
 def test_tol_mode_with_mesh_plan(capsys):
     recover.main([
         "--n", "512", "--batch", "2", "--method", "fista", "--iters", "80",

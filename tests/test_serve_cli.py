@@ -5,8 +5,16 @@ via ``--fake-devices`` as a script; here local engines and a 1-device mesh
 exercise the same dispatch routing.
 """
 
+import pytest
 
 from repro.launch import serve
+
+
+@pytest.fixture(autouse=True)
+def _placed_compile_cache(monkeypatch, tmp_path):
+    """main() configures the compile cache; a placed directory keeps it
+    from re-pointing this test process's JAX config."""
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "jax_cache"))
 
 
 def test_serve_local_with_static_comparison(capsys):

@@ -34,6 +34,17 @@ def _fresh_counters():
     yield
 
 
+@pytest.fixture(autouse=True)
+def _score_cpu_mesh_as_v5e(monkeypatch):
+    """The tuner scores with the peak rates of its mesh's device kind and
+    refuses kinds it has no entry for; these CPU meshes are scored with
+    the v5e entry, named here explicitly."""
+    from repro.launch import roofline
+
+    kind = jax.devices()[0].device_kind
+    monkeypatch.setitem(roofline.PEAKS, kind, roofline.PEAKS[roofline.V5E])
+
+
 @pytest.fixture
 def cache(tmp_path):
     return tune.PlanCache(str(tmp_path / "plan_cache.json"))
@@ -418,9 +429,12 @@ def test_dcn_bytes_policy():
 def test_two_tier_model_ranks_hier_above_flat():
     """Under the two-tier model a hier block (full payload on ICI + 1/H on
     DCN) must outscore the flat block (full payload on DCN) whenever
-    DCN_BW < ICI_BW / H — asserted on synthetic costs through the real
-    scoring math, pinning the win condition the dryrun table reports."""
-    from repro.launch.roofline import DCN_BW, ICI_BW, model_block_times
+    dcn_bw < ici_bw / H — asserted on synthetic costs through the real
+    scoring math with the v5e peaks, pinning the win condition the dryrun
+    table reports."""
+    from repro.launch.roofline import PEAKS, V5E, model_block_times
+
+    pk = PEAKS[V5E]
 
     class _Cost:
         flops = 1e9
@@ -432,13 +446,47 @@ def test_two_tier_model_ranks_hier_above_flat():
     flat_cost.collective_bytes = {"all-to-all": B}
     hier_cost.collective_bytes = {"all-to-all": B,
                                   "collective-permute": B / H}
-    assert DCN_BW < ICI_BW / H  # the regime the constants encode
-    t_flat = model_block_times(flat_cost, dcn_bytes=B)
-    t_hier = model_block_times(hier_cost, dcn_bytes=B / H)
+    assert pk.dcn_bw < pk.ici_bw / H  # the regime the constants encode
+    t_flat = model_block_times(flat_cost, dcn_bytes=B, peaks=pk)
+    t_hier = model_block_times(hier_cost, dcn_bytes=B / H, peaks=pk)
     assert t_hier["collective_s"] < t_flat["collective_s"]
     assert t_hier["dcn_collective_s"] == pytest.approx(
         t_flat["dcn_collective_s"] / H)
     # and with no DCN bytes the split reproduces the single-tier term
-    t0 = model_block_times(flat_cost)
-    assert t0["collective_s"] == B / ICI_BW == t0["ici_collective_s"]
+    t0 = model_block_times(flat_cost, peaks=pk)
+    assert t0["collective_s"] == B / pk.ici_bw == t0["ici_collective_s"]
     assert t0["dcn_collective_s"] == 0.0
+
+
+# ---------------------------------------------------------------------------
+# peak-rate table keyed by device kind
+# ---------------------------------------------------------------------------
+
+
+def test_peaks_table_v5e_entry():
+    from repro.launch.roofline import PEAKS, peaks_for
+
+    pk = peaks_for("TPU v5 lite")
+    assert pk is PEAKS["TPU v5 lite"]
+    assert (pk.flops, pk.hbm_bw, pk.ici_bw) == (197e12, 819e9, 50e9)
+
+
+@pytest.mark.parametrize("kind", ["cpu", "TPU v4", "", "tpu v5 lite"])
+def test_peaks_table_unknown_kind_raises(kind, monkeypatch):
+    from repro.launch import roofline
+
+    monkeypatch.setattr(roofline, "PEAKS", {"TPU v5 lite": roofline.PEAKS["TPU v5 lite"]})
+    with pytest.raises(ValueError, match="no peak rates"):
+        roofline.peaks_for(kind)
+
+
+def test_tuner_refuses_a_device_kind_without_peaks(monkeypatch):
+    """Scoring on a mesh whose device kind has no peak entry is an error,
+    not a silent default."""
+    from repro.launch import roofline
+
+    monkeypatch.delitem(roofline.PEAKS, jax.devices()[0].device_kind)
+    mesh = make_mesh((1,), ("model",))
+    cands = tune.candidate_configs(_problem().op, mesh, pins={"rfft": False})
+    with pytest.raises(ValueError, match="no peak rates"):
+        tune.score_candidates(mesh, cands[:1], batch=1, iters=2)
