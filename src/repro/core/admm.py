@@ -171,16 +171,22 @@ def cpadmm_step(
     duals:     mu += tau1 (v - Cx);  nu += tau2 (x - z)
     (the last three are :func:`cpadmm_tail` — one fused Pallas pass on the
     kernel backend, kernels/cpadmm_tail)
+
+    The three parts are the named scopes ``cpadmm.xupdate``, ``cpadmm.cx``
+    and ``cpadmm.tail`` of the compiled program's ``op_name`` metadata.
     """
     C = op.circ
     n = op.n
-    rhs = p.rho * C.rmatvec(state.v + state.mu) + p.sigma * (state.z - state.nu)
-    x = _apply_spec(const.b_spec, rhs, n)
+    with jax.named_scope("cpadmm.xupdate"):
+        rhs = p.rho * C.rmatvec(state.v + state.mu) + p.sigma * (state.z - state.nu)
+        x = _apply_spec(const.b_spec, rhs, n)
 
-    cx = C.matvec(x)
-    v, z, mu, nu = cpadmm_tail(
-        x, cx, const.d_diag, const.Pty, state.mu, state.nu, p, prox=prox
-    )
+    with jax.named_scope("cpadmm.cx"):
+        cx = C.matvec(x)
+    with jax.named_scope("cpadmm.tail"):
+        v, z, mu, nu = cpadmm_tail(
+            x, cx, const.d_diag, const.Pty, state.mu, state.nu, p, prox=prox
+        )
     return CpadmmState(x=x, v=v, z=z, mu=mu, nu=nu)
 
 

@@ -33,6 +33,7 @@ from repro.kernels.circulant_matvec.ops import circulant_matvec
 from repro.kernels.cpadmm_tail.ops import fused_cpadmm_tail
 from repro.kernels.soft_threshold.ops import fused_ista_update
 from repro.kernels.spectral_pointwise.ops import spectral_update
+from repro.ops.spectral import irfft, rfft
 
 # wire-compressed collectives (plan knob wire_dtype=): the demote-pack /
 # promote-unpack pair the distributed transforms fuse around every transpose
@@ -74,21 +75,27 @@ def cpadmm_step_pallas(
     *,
     interpret: bool = True,
 ) -> CpadmmState:
-    """CPADMM iteration: spectral_pointwise x-update + one fused tail pass."""
-    n = op.n
-    vm = jnp.fft.rfft(state.v + state.mu, axis=-1)
-    zn = jnp.fft.rfft(state.z - state.nu, axis=-1)
-    x_spec = spectral_update(
-        op.circ.spec, const.b_spec.astype(op.circ.spec.dtype), vm, zn,
-        p.rho, p.sigma, interpret=interpret,
-    )
-    x = jnp.fft.irfft(x_spec, n=n, axis=-1)
+    """CPADMM iteration: spectral_pointwise x-update + one fused tail pass.
 
-    cx = op.circ.matvec(x)  # spectral apply: never the direct kernel
+    Its three parts are the named scopes ``cpadmm.xupdate``, ``cpadmm.cx``
+    and ``cpadmm.tail``, as in :func:`repro.core.admm.cpadmm_step`."""
+    n = op.n
+    with jax.named_scope("cpadmm.xupdate"):
+        vm = rfft(state.v + state.mu, n)
+        zn = rfft(state.z - state.nu, n)
+        x_spec = spectral_update(
+            op.circ.spec, const.b_spec.astype(op.circ.spec.dtype), vm, zn,
+            p.rho, p.sigma, interpret=interpret,
+        )
+        x = irfft(x_spec, n)
+
+    with jax.named_scope("cpadmm.cx"):
+        cx = op.circ.matvec(x)  # spectral apply: never the direct kernel
     # the entire elementwise tail (v-update, threshold, both duals) is one
     # VMEM-resident kernel pass — kernels/cpadmm_tail
-    v, z, mu, nu = fused_cpadmm_tail(
-        x, cx, const.d_diag, const.Pty, state.mu, state.nu,
-        p.rho, p.alpha / p.sigma, p.tau1, p.tau2, interpret=interpret,
-    )
+    with jax.named_scope("cpadmm.tail"):
+        v, z, mu, nu = fused_cpadmm_tail(
+            x, cx, const.d_diag, const.Pty, state.mu, state.nu,
+            p.rho, p.alpha / p.sigma, p.tau1, p.tau2, interpret=interpret,
+        )
     return CpadmmState(x=x, v=v, z=z, mu=mu, nu=nu)

@@ -250,7 +250,8 @@ def _freeze_converged(new_state, old_state, active: Array, batch: Tuple[int, ...
             return jnp.where(m, new_leaf, old_leaf)
         return new_leaf
 
-    return jax.tree.map(sel, new_state, old_state)
+    with jax.named_scope("until.freeze"):
+        return jax.tree.map(sel, new_state, old_state)
 
 
 class UntilState(NamedTuple):
@@ -316,18 +317,21 @@ def until_step(
     """One masked iteration: step active slots, freeze the rest, update each
     active slot's age and relative change.  Frozen slots keep their last
     delta (the reporting value; a recycled slot gets a fresh inf via
-    :func:`rearm_slots`, never this stale one)."""
-    active = until_active(u, tol, min_iters, max_iters)
+    :func:`rearm_slots`, never this stale one).  The liveness mask, the
+    norms and the selects are the named scope ``until.test``."""
+    with jax.named_scope("until.test"):
+        active = until_active(u, tol, min_iters, max_iters)
     new = _freeze_converged(stepper.step(u.state), u.state, active, batch)
-    x_old = stepper.extract(u.state)
-    x_new = stepper.extract(new)
-    num = jnp.linalg.norm(x_new - x_old, axis=-1)
-    den = jnp.linalg.norm(x_old, axis=-1) + 1e-12
-    return UntilState(
-        state=new,
-        age=jnp.where(active, u.age + 1, u.age),
-        delta=jnp.where(active, num / den, u.delta),
-    )
+    with jax.named_scope("until.test"):
+        x_old = stepper.extract(u.state)
+        x_new = stepper.extract(new)
+        num = jnp.linalg.norm(x_new - x_old, axis=-1)
+        den = jnp.linalg.norm(x_old, axis=-1) + 1e-12
+        return UntilState(
+            state=new,
+            age=jnp.where(active, u.age + 1, u.age),
+            delta=jnp.where(active, num / den, u.delta),
+        )
 
 
 def rearm_slots(

@@ -29,13 +29,16 @@ Array = jax.Array
 
 
 def rfft(x: Array, n: int) -> Array:
-    """Length-``n`` real FFT along the trailing axis."""
-    return jnp.fft.rfft(x, n=n, axis=-1)
+    """Length-``n`` real FFT along the trailing axis (scope ``transform``
+    in the compiled program's ``op_name`` metadata, as :func:`irfft`)."""
+    with jax.named_scope("transform"):
+        return jnp.fft.rfft(x, n=n, axis=-1)
 
 
 def irfft(x: Array, n: int) -> Array:
     """Length-``n`` inverse real FFT along the trailing axis."""
-    return jnp.fft.irfft(x, n=n, axis=-1)
+    with jax.named_scope("transform"):
+        return jnp.fft.irfft(x, n=n, axis=-1)
 
 
 def apply_spectrum(spec: Array, x: Array, n: int) -> Array:

@@ -24,10 +24,13 @@ and every per-slot array are traced arguments, so admission never re-jits.
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.solvers import (
     RecoveryProblem,
@@ -41,8 +44,50 @@ from repro.core.solvers import (
 from .request import RecoveryRequest, RecoveryResult
 
 
+def make_round_fn(op, plan, method, batch, round_iters, **solver_kw):
+    """The engine's jitted round: up to ``round_iters`` masked iterations of
+    every active lane, then the lanes' iterates.
+
+    The operator is closed over; the measurement rows ``y``, the carry and
+    the per-slot contracts are traced arguments, so admitting a new row
+    never re-compiles.  The carry ``u`` is donated: the round writes the
+    next carry into its buffers.  ``solver_kw`` are ``make_stepper``'s
+    ``alpha`` / ``rho`` / ``sigma``.
+    """
+
+    @functools.partial(jax.jit, donate_argnums=1)
+    def round_fn(y, u, tol, mn, mx):
+        stepper = make_stepper(RecoveryProblem(op=op, y=y), method, plan=plan,
+                               **solver_kw)
+
+        def cond(c):
+            u, k = c
+            return jnp.logical_and(
+                k < round_iters, jnp.any(until_active(u, tol, mn, mx))
+            )
+
+        def body(c):
+            u, k = c
+            return until_step(stepper, u, tol, mn, mx, batch), k + 1
+
+        (u, _) = jax.lax.while_loop(cond, body, (u, jnp.int32(0)))
+        return u, stepper.extract(u.state)
+
+    return round_fn
+
+
 class BatchEngine:
-    """``slots`` lanes of one batched solver, recycled round by round."""
+    """``slots`` lanes of one batched solver, recycled round by round.
+
+    ``stats`` counts the engine's work on the host, where it happens, with
+    no device work or sync of its own: admissions, recycled admissions,
+    rounds, slot iterations stepped (from the ``age`` vector each harvest
+    reads), results harvested, seconds requests queued before admission,
+    and the bytes the harvest read from the device.  Each of
+    :meth:`admit`, :meth:`run_round` and :meth:`harvest` is a profiler span
+    (``serve.admit``, ``serve.round``, ``serve.harvest``), recorded only
+    while a ``jax.profiler`` trace runs.
+    """
 
     def __init__(
         self,
@@ -83,56 +128,49 @@ class BatchEngine:
         self._requests: List[Optional[RecoveryRequest]] = [None] * self.slots
         self._admitted_at: List[Optional[float]] = [None] * self.slots
         self._slot_used = [False] * self.slots
+        # each lane's age as of the last harvest (0 once re-armed): the
+        # baseline slot_iters is counted against
+        self._age = np.zeros((self.slots,), np.int64)
 
-        self.stats: Dict[str, int] = {
+        self.stats: Dict[str, float] = {
             "admitted": 0,  # requests that reached a slot
             "recycled": 0,  # admissions into a lane freed mid-run
             "rounds": 0,  # jitted round launches
             "slot_iters": 0,  # sum of per-slot iterations actually stepped
+            "harvested": 0,  # results returned
+            "queue_wait_s": 0.0,  # sum of admission time - arrival time
+            "d2h_bytes": 0,  # bytes harvest read from the device
         }
 
-        def build_stepper(y):
-            return make_stepper(
-                RecoveryProblem(op=op, y=y), method,
-                alpha=alpha, rho=rho, sigma=sigma, plan=plan,
-            )
-
+        solver_kw = dict(alpha=alpha, rho=rho, sigma=sigma)
         # the init carry: solver-state zeros + age 0 + delta inf — both the
         # engine's starting point and the value re-armed into recycled slots
         # (solver inits are y-independent, so one init serves every request)
-        stepper0 = build_stepper(self._y)
-        self._u, self._batch = until_init(stepper0)
-        self._u_init = self._u
-        self._x = stepper0.extract(self._u.state)  # (slots, n) last extract
+        stepper0 = make_stepper(RecoveryProblem(op=op, y=self._y), method,
+                                plan=plan, **solver_kw)
+        self._u_init, self._batch = until_init(stepper0)
+        # the live carry is donated to each re-arm and round, so it owns
+        # buffers of its own, apart from the init carry and from each other
+        self._u = jax.tree.map(jnp.copy, self._u_init)
+        self._x = stepper0.extract(self._u_init.state)  # (slots, n) last extract
 
-        round_iters_ = self.round_iters
         batch = self._batch
+        self._round_fn = make_round_fn(op, plan, method, batch,
+                                       self.round_iters, **solver_kw)
 
-        @jax.jit
-        def round_fn(y, u, tol, mn, mx):
-            # the stepper is rebuilt under the trace so y is a traced
-            # argument: admitting a new measurement row never re-compiles
-            stepper = build_stepper(y)
-
-            def cond(c):
-                u, k = c
-                return jnp.logical_and(
-                    k < round_iters_, jnp.any(until_active(u, tol, mn, mx))
-                )
-
-            def body(c):
-                u, k = c
-                return until_step(stepper, u, tol, mn, mx, batch), k + 1
-
-            (u, _) = jax.lax.while_loop(cond, body, (u, jnp.int32(0)))
-            return u, stepper.extract(u.state)
-
-        @jax.jit
+        @functools.partial(jax.jit, donate_argnums=0)
         def rearm_fn(u, admit):
             return rearm_slots(u, self._u_init, admit, batch)
 
-        self._round_fn = round_fn
         self._rearm_fn = rearm_fn
+
+    def lower_round(self):
+        """The round program lowered at the engine's current arguments: the
+        same avals and shardings a round runs with, so compiling it finds
+        the compilation cache's entry.  Nothing runs."""
+        return self._round_fn.lower(
+            self._y, self._u, self._tol, self._min, self._max
+        )
 
     # -- occupancy ---------------------------------------------------------
     @property
@@ -147,24 +185,27 @@ class BatchEngine:
         """Place ``req`` into a free slot, re-arming that lane's state."""
         if self._requests[slot] is not None:
             raise RuntimeError(f"slot {slot} is occupied")
-        y = jnp.asarray(req.y, self._y.dtype)
-        if self._scatter and y.shape[-1] != self._y_len:
-            y = self.op.project_back(y)
-        if y.shape[-1] != self._y_len:
-            raise ValueError(
-                f"request {req.request_id!r}: measurement length "
-                f"{y.shape[-1]} does not fit this bucket's operator "
-                f"(expects {self._y_len})"
-            )
-        self._y = self._y.at[slot].set(y)
-        self._tol = self._tol.at[slot].set(req.tol)
-        self._min = self._min.at[slot].set(req.min_iters)
-        self._max = self._max.at[slot].set(req.max_iters)
-        admit_mask = jnp.zeros((self.slots,), bool).at[slot].set(True)
-        self._u = self._rearm_fn(self._u, admit_mask)
+        with TraceAnnotation("serve.admit", request_id=req.request_id):
+            y = jnp.asarray(req.y, self._y.dtype)
+            if self._scatter and y.shape[-1] != self._y_len:
+                y = self.op.project_back(y)
+            if y.shape[-1] != self._y_len:
+                raise ValueError(
+                    f"request {req.request_id!r}: measurement length "
+                    f"{y.shape[-1]} does not fit this bucket's operator "
+                    f"(expects {self._y_len})"
+                )
+            self._y = self._y.at[slot].set(y)
+            self._tol = self._tol.at[slot].set(req.tol)
+            self._min = self._min.at[slot].set(req.min_iters)
+            self._max = self._max.at[slot].set(req.max_iters)
+            admit_mask = jnp.zeros((self.slots,), bool).at[slot].set(True)
+            self._u = self._rearm_fn(self._u, admit_mask)
+        self._age[slot] = 0
         self._requests[slot] = req
         self._admitted_at[slot] = now
         self.stats["admitted"] += 1
+        self.stats["queue_wait_s"] += now - req.arrival_time
         if self._slot_used[slot]:
             self.stats["recycled"] += 1
         self._slot_used[slot] = True
@@ -181,48 +222,62 @@ class BatchEngine:
         """Advance every active lane up to ``round_iters`` masked iterations."""
         if not self.busy:
             return
-        age_before = int(jnp.sum(self._u.age))
-        self._u, self._x = self._round_fn(
-            self._y, self._u, self._tol, self._min, self._max
-        )
-        jax.block_until_ready(self._x)
+        with TraceAnnotation("serve.round"):
+            self._u, self._x = self._round_fn(
+                self._y, self._u, self._tol, self._min, self._max
+            )
+            with TraceAnnotation("serve.round.wait"):
+                jax.block_until_ready(self._x)
         self.stats["rounds"] += 1
-        self.stats["slot_iters"] += int(jnp.sum(self._u.age)) - age_before
 
     # -- harvest -----------------------------------------------------------
+    def _read(self, *arrays) -> list:
+        """``device_get`` of each array, counted in ``d2h_bytes``."""
+        host = [jax.device_get(a) for a in arrays]
+        self.stats["d2h_bytes"] += sum(h.nbytes for h in host)
+        return host
+
     def harvest(self, now: float) -> List[RecoveryResult]:
         """Collect finished lanes: converged / budget-exhausted lanes, plus
         any whose deadline has passed (flagged partial results)."""
         if not self.busy:
             return []
-        age = jax.device_get(self._u.age)
-        delta = jax.device_get(self._u.delta)
-        tol = jax.device_get(self._tol)
-        mn = jax.device_get(self._min)
-        mx = jax.device_get(self._max)
-        out: List[RecoveryResult] = []
-        x_host = None
-        for i, req in enumerate(self._requests):
-            if req is None:
-                continue
-            inactive = age[i] >= mx[i] or (age[i] >= mn[i] and delta[i] <= tol[i])
-            expired = req.deadline is not None and now >= req.deadline
-            if not (inactive or expired):
-                continue
-            if x_host is None:
-                x_host = jax.device_get(self._x)
-            converged = bool(delta[i] <= tol[i] and age[i] >= mn[i])
-            out.append(RecoveryResult(
-                request_id=req.request_id,
-                x=x_host[i],
-                iterations=int(age[i]),
-                delta=float(delta[i]),
-                converged=converged,
-                deadline_expired=bool(expired and not converged),
-                arrival_time=req.arrival_time,
-                admitted_time=self._admitted_at[i],
-                finish_time=now,
-                bucket=self.bucket,
-            ))
-            self.park(i)
+        with TraceAnnotation("serve.harvest"):
+            with TraceAnnotation("serve.harvest.poll"):
+                age, delta, tol, mn, mx = self._read(
+                    self._u.age, self._u.delta, self._tol, self._min, self._max
+                )
+            self.stats["slot_iters"] += int(np.sum(age - self._age))
+            self._age = np.array(age, np.int64)
+            done = []
+            for i, req in enumerate(self._requests):
+                if req is None:
+                    continue
+                inactive = age[i] >= mx[i] or (age[i] >= mn[i] and delta[i] <= tol[i])
+                expired = req.deadline is not None and now >= req.deadline
+                if inactive or expired:
+                    done.append((i, req, expired))
+            if not done:
+                return []
+            ids = ",".join(req.request_id for _, req, _ in done)
+            with TraceAnnotation("serve.harvest.fetch", lanes=len(done),
+                                 request_id=ids):
+                (x_host,) = self._read(self._x)
+            out: List[RecoveryResult] = []
+            for i, req, expired in done:
+                converged = bool(delta[i] <= tol[i] and age[i] >= mn[i])
+                out.append(RecoveryResult(
+                    request_id=req.request_id,
+                    x=x_host[i],
+                    iterations=int(age[i]),
+                    delta=float(delta[i]),
+                    converged=converged,
+                    deadline_expired=bool(expired and not converged),
+                    arrival_time=req.arrival_time,
+                    admitted_time=self._admitted_at[i],
+                    finish_time=now,
+                    bucket=self.bucket,
+                ))
+                self.park(i)
+        self.stats["harvested"] += len(out)
         return out

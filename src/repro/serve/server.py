@@ -23,6 +23,7 @@ import heapq
 from typing import Any, Dict, List, Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from .engine import BatchEngine
 from .request import Clock, RecoveryRequest, RecoveryResult, WallClock
@@ -185,26 +186,28 @@ class RecoveryServer:
         """One scheduling round: admit → iterate → harvest, every bucket.
 
         Returns the results harvested this round (also appended to
-        ``self.results``).
+        ``self.results``).  The round is the profiler span ``serve.step``,
+        holding the engines' own spans.
         """
-        now = self.clock.now()
-        harvested: List[RecoveryResult] = []
-        for key, q in list(self._queues.items()):
-            self._expire_queued(key, now)
-            q = self._queues[key]
-            if not q and key not in self.engines:
-                continue
-            if q:
-                eng = self._engine_for(key, q[0][2])
-                for slot in eng.free_slots():
-                    if not q:
-                        break
-                    _, _, req = heapq.heappop(q)
-                    eng.admit(slot, req, now)
-        for eng in self.engines.values():
-            eng.run_round()
-            got = eng.harvest(self.clock.now())
-            harvested.extend(got)
+        with TraceAnnotation("serve.step"):
+            now = self.clock.now()
+            harvested: List[RecoveryResult] = []
+            for key, q in list(self._queues.items()):
+                self._expire_queued(key, now)
+                q = self._queues[key]
+                if not q and key not in self.engines:
+                    continue
+                if q:
+                    eng = self._engine_for(key, q[0][2])
+                    for slot in eng.free_slots():
+                        if not q:
+                            break
+                        _, _, req = heapq.heappop(q)
+                        eng.admit(slot, req, now)
+            for eng in self.engines.values():
+                eng.run_round()
+                got = eng.harvest(self.clock.now())
+                harvested.extend(got)
         self.results.extend(harvested)
         return harvested
 
@@ -235,11 +238,19 @@ class RecoveryServer:
 
     # -- stats -------------------------------------------------------------
     def stats(self) -> Dict[str, Any]:
+        """Each engine's counters, and their sums over the engines in
+        ``total``, which also holds ``waiting_s``: the seconds the requests
+        still queued have waited so far.  ``queue_wait_s + waiting_s`` is
+        every second of queueing up to now, so its change over an interval
+        counts the queue's wait inside it, admitted or not."""
         per_bucket = {k: dict(e.stats) for k, e in self.engines.items()}
-        total = {"admitted": 0, "recycled": 0, "rounds": 0, "slot_iters": 0}
+        total: Dict[str, float] = {}
         for s in per_bucket.values():
-            for k in total:
-                total[k] += s[k]
+            for k, v in s.items():
+                total[k] = total.get(k, 0) + v
+        now = self.clock.now()
+        total["waiting_s"] = sum(now - req.arrival_time
+                                 for q in self._queues.values() for _, _, req in q)
         return {"buckets": len(self.engines), "total": total,
                 "per_bucket": per_bucket}
 
