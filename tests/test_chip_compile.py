@@ -6,7 +6,8 @@ Mosaic tiling rules ((8, 128) blocks, XLA's 1024-element tiles for long
 paths run — the n = 2^24, B = 4 paper-regime block, the n = 2^20 serving
 batch, four-step wire chunks — for one chip of a described ``v5e:2x2``
 topology, and asserts the compiled program holds the kernel
-(``tpu_custom_call``).
+(``tpu_custom_call``).  The inverse real FFT is compiled the same way, to
+assert that its program holds no lane-axis ``reverse``.
 
 The topology is described inside a module-scoped fixture, never while a
 module is imported: only one process at a time may load the TPU compiler
@@ -22,6 +23,7 @@ from repro.kernels.cpadmm_tail.ops import fused_cpadmm_tail
 from repro.kernels.soft_threshold.ops import fused_admm_update, fused_ista_update
 from repro.kernels.spectral_pointwise.ops import spectral_update
 from repro.kernels.wire_pack.ops import pack_wire, unpack_wire
+from repro.ops.spectral import irfft
 
 N_PAPER = 1 << 24  # paper-regime signal length (chip_smoke phase a)
 N_SERVE = 1 << 20  # served signal length (chip_smoke phase c)
@@ -147,3 +149,21 @@ def test_wire_pack_fp16_stays_on_xla_on_tpu(one_chip, monkeypatch):
     bf16 = _compiled_text(lambda z: pack_wire(z, "bf16"), one_chip, (shape, C64))
     assert "tpu_custom_call" not in f16 and "tpu_custom_call" not in f16_back
     assert "tpu_custom_call" in bf16
+
+
+@pytest.mark.parametrize(
+    "batch,n",
+    [
+        ((8,), N_SERVE),  # serving slots: c64[8, 2^19 + 1] -> f32[8, 2^20]
+        ((1,), N_PAPER),  # paper regime: c64[1, 2^23 + 1] -> f32[1, 2^24]
+    ],
+)
+def test_irfft_has_no_lane_reverse(one_chip, batch, n):
+    """The chip's lowering of jnp.fft.irfft rebuilds the mirrored half
+    spectrum with two lane-axis reverses, which run far below the memory
+    roofline; the one-sided inverse runs the same complex FFT without
+    them."""
+    text = _compiled_text(lambda x: irfft(x, n), one_chip,
+                          (batch + (n // 2 + 1,), C64))
+    assert "convolution(" in text  # the chip's FFT is there
+    assert "reverse(" not in text

@@ -19,6 +19,7 @@ from repro.core.circulant import (
     random_omega,
     romberg_circulant,
 )
+from repro.ops.spectral import irfft
 
 SETTINGS = dict(max_examples=25, deadline=None)
 
@@ -86,6 +87,44 @@ def test_gram_compose_inverse(n, seed):
         np.linalg.inv(np.asarray(B.to_dense())),
         atol=1e-4,
     )
+
+
+@pytest.mark.parametrize(
+    "batch,n,nf",
+    [
+        ((), 16, 9),  # even n: DC and Nyquist bins
+        ((), 17, 9),  # odd n: no Nyquist bin
+        ((3,), 1024, 513),  # batched
+        ((2, 3), 33, 17),  # two leading axes
+        ((3,), 64, 40),  # longer than n//2 + 1: cropped
+        ((3,), 63, 20),  # shorter than n//2 + 1: zero-padded
+    ],
+)
+def test_irfft_matches_jnp(batch, n, nf):
+    """The one-sided inverse in repro.ops.spectral is jnp.fft.irfft: both
+    ignore the imaginary parts of the DC and Nyquist bins, and the
+    circulant algebra built on it still matches its dense oracle."""
+    k1, k2, k3, k4 = jax.random.split(jax.random.PRNGKey(n + nf), 4)
+    X = jax.lax.complex(jax.random.normal(k1, batch + (nf,)),
+                        jax.random.normal(k2, batch + (nf,)))
+    assert float(jnp.min(jnp.abs(X[..., 0].imag))) > 0  # imaginary DC present
+    got, want = irfft(X, n), jnp.fft.irfft(X, n=n, axis=-1)
+    assert got.dtype == jnp.float32 and got.shape == batch + (n,)
+    assert float(jnp.linalg.norm(got - want) / jnp.linalg.norm(want)) <= 1e-6
+    # the bins without a mirror count by their real parts alone
+    real_dc = X.at[..., 0].set(X[..., 0].real)
+    if n % 2 == 0 and nf > n // 2:
+        real_dc = real_dc.at[..., n // 2].set(X[..., n // 2].real)
+    np.testing.assert_allclose(np.asarray(irfft(real_dc, n)), np.asarray(got),
+                               rtol=1e-5, atol=1e-6)
+
+    C = gaussian_circulant(k3, n)
+    col = np.asarray(C.col)
+    dense = col[(np.arange(n)[:, None] - np.arange(n)[None, :]) % n]
+    x = np.asarray(jax.random.normal(k4, batch + (n,)))
+    scale = max(1.0, float(np.abs(x @ dense.T).max()))
+    np.testing.assert_allclose(np.asarray(C.matvec(x)), x @ dense.T, atol=2e-4 * scale)
+    np.testing.assert_allclose(np.asarray(C.rmatvec(x)), x @ dense, atol=2e-4 * scale)
 
 
 def test_operator_norm_exact():
